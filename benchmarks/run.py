@@ -38,6 +38,10 @@ def main() -> None:
     args = ap.parse_args()
     t0 = time.time()
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     from benchmarks import (
         bench_curie,
         bench_dvfs,

@@ -1791,6 +1791,34 @@ class PendingSweep:
         return self._batch
 
 
+def _sweep_program(config: EngineConfig, cap: int, devices=None):
+    """The jitted grid program: :func:`run_sim` vmapped over the stacked
+    scenario axis (``s0`` shared), unsharded when ``devices`` is None.
+
+    Given a device list, the scenario axis is lowered onto a 1-D mesh over
+    it: each device runs the identical vmapped program over its (K+pad)/D
+    scenario rows; s0 is replicated. vmap is elementwise per scenario, so
+    per-scenario results are bit-exact vs the unsharded dispatch
+    (§Device-sharded sweeps)."""
+    run_k = jax.vmap(
+        lambda s, c: run_sim(s, c, config, max_batches=cap),
+        in_axes=(None, 0),
+    )
+    if devices is None:
+        return jax.jit(run_k)
+    mesh = jax.sharding.Mesh(np.asarray(devices), ("scenario",))
+    sharded = jax.sharding.PartitionSpec("scenario")
+    return jax.jit(
+        jax.shard_map(
+            run_k,
+            mesh=mesh,
+            in_specs=(jax.sharding.PartitionSpec(), sharded),
+            out_specs=sharded,
+            check_vma=False,
+        )
+    )
+
+
 def sweep_async(
     platform: PlatformSpec,
     workload: Workload,
@@ -1839,33 +1867,9 @@ def sweep_async(
     if fn is None:
         if len(_SWEEP_FNS) >= _SWEEP_CACHE_SIZE:
             _SWEEP_FNS.popitem(last=False)  # evict least-recently-used
-        run_k = jax.vmap(
-            lambda s, c: run_sim(s, c, config, max_batches=cap),
-            in_axes=(None, 0),
+        fn = _sweep_program(
+            config, cap, None if D is None else jax.devices()[:D]
         )
-        if D is None:
-            fn = jax.jit(run_k)
-        else:
-            # lower the stacked scenario axis onto a 1-D device mesh: each
-            # device runs the identical vmapped program over its (K+pad)/D
-            # scenario rows; s0 is replicated. vmap is elementwise per
-            # scenario, so per-scenario results are bit-exact vs the
-            # unsharded dispatch (§Device-sharded sweeps)
-            from jax.experimental.shard_map import shard_map
-
-            mesh = jax.sharding.Mesh(
-                np.asarray(jax.devices()[:D]), ("scenario",)
-            )
-            sharded = jax.sharding.PartitionSpec("scenario")
-            fn = jax.jit(
-                shard_map(
-                    run_k,
-                    mesh=mesh,
-                    in_specs=(jax.sharding.PartitionSpec(), sharded),
-                    out_specs=sharded,
-                    check_rep=False,
-                )
-            )
     _SWEEP_FNS[key] = fn
     out = fn(s0, stacked)  # asynchronous dispatch — not blocked here
     cache_size = getattr(fn, "_cache_size", None)

@@ -234,20 +234,18 @@ def _maybe_shard_update(update, sims0: SimState, D) -> Callable:
     state/key replicated, env batch (and the reset pool) sharded."""
     if D is None:
         return lambda ts: update(ts, sims0)
-    from jax.experimental.shard_map import shard_map
-
     from repro.core.rl.env import rollout_mesh
 
     P = jax.sharding.PartitionSpec
     ts_spec = TrainState(
         params=P(), opt_state=P(), env_states=P("env"), obs=P("env"), key=P()
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         update,
         mesh=rollout_mesh(D),
         in_specs=(ts_spec, P("env")),
         out_specs=(ts_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return lambda ts: sharded(ts, sims0)
 

@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 from repro.core.types import INF_TIME, N_STATES, SWITCHING_OFF, SWITCHING_ON
 
 PAD_STATE = 7  # padding nodes: zero power, never transitioning
@@ -206,7 +204,7 @@ def event_fuse(
             jax.ShapeDtypeStruct((e_pad, 1), jnp.float32),
             jax.ShapeDtypeStruct((e_pad, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -262,7 +260,7 @@ def event_fuse_occ(
             jax.ShapeDtypeStruct((e_pad, n_groups * 8), jnp.float32),
             jax.ShapeDtypeStruct((e_pad, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -305,7 +303,7 @@ def event_fuse_ledger(
             jax.ShapeDtypeStruct((e_pad, 8), jnp.float32),
             jax.ShapeDtypeStruct((e_pad, 1), jnp.int32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -177,7 +175,7 @@ def flash_attention(
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -38,8 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _ssd_kernel(
     q_ref,  # (1, 1, C, dk)
@@ -142,7 +140,7 @@ def ssd_scan(
             jax.ShapeDtypeStruct((b, h, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

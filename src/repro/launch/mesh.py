@@ -10,17 +10,26 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape, axes):
+    """A mesh whose axes the compiler shards automatically: the sharding
+    rules here name mesh axes in ``NamedSharding``s and ``with mesh:``
+    blocks, which explicit axes (``jax.make_mesh``'s default) reject."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over whatever devices exist (tests / local runs)."""
     n = len(jax.devices())
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return _auto_mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 # TPU v5e roofline constants (per chip)

@@ -44,6 +44,7 @@ import json
 import os
 from typing import Any, Dict, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import engine
@@ -56,6 +57,7 @@ from repro.experiments import (
     resolve_platform,
     resolve_workload,
 )
+from repro.launch.compile_cache import use_compile_cache
 
 
 # single-run config keys (the experiment layer validates its own spec)
@@ -189,6 +191,26 @@ def _resolve_rl_policy(pol, config, plat):
     return dataclasses.replace(pol, controller=controller), rl
 
 
+def _device_bytes_limit() -> Optional[int]:
+    """Bytes the default device can hold, where its backend reports it."""
+    stats = jax.devices()[0].memory_stats()
+    return None if stats is None else stats.get("bytes_limit")
+
+
+def _check_gantt_fits(cap: int, n_nodes: int) -> None:
+    """Refuse, before compiling, a Gantt log the device cannot hold: the
+    log is two i32[cap, N] per-batch snapshots (``engine.run_sim_gantt``)."""
+    need = 2 * cap * n_nodes * 4
+    limit = _device_bytes_limit()
+    if limit is not None and need > limit:
+        raise ValueError(
+            f"the Gantt log needs {need} bytes of device memory (2 x "
+            f"{cap} batches x {n_nodes} nodes x i32), more than the "
+            f"device's {limit}; set \"gantt\": false in the config to run "
+            "without it"
+        )
+
+
 def run(config: Dict[str, Any]) -> Dict[str, Any]:
     _validate_keys(config)
     wl = resolve_workload(config["workload"])
@@ -229,6 +251,7 @@ def run(config: Dict[str, Any]) -> Dict[str, Any]:
     const = engine.make_const(plat, ecfg, specialize=True)
     cap = engine.default_batch_cap(len(wl))
     if ecfg.record_gantt:
+        _check_gantt_fits(cap, plat.nb_nodes)
         s, log = engine.run_sim_gantt(s0, const, ecfg, max_batches=cap)
         intervals = intervals_from_log(log)
         write_csv(intervals, os.path.join(out_dir, "gantt.csv"))
@@ -304,6 +327,7 @@ def main(argv=None):
     ap.add_argument("--terminate-overrun", action="store_true")
     ap.add_argument("--out", default="out/sim")
     args = ap.parse_args(argv)
+    use_compile_cache()
     try:
         from_label(args.scheduler)
     except KeyError as e:

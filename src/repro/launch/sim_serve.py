@@ -35,6 +35,7 @@ from typing import Any, Dict, List, Optional
 from repro.core import engine
 from repro.experiments import Experiment, StreamingRun
 from repro.experiments import run as run_experiment
+from repro.launch.compile_cache import use_compile_cache
 
 
 @dataclasses.dataclass
@@ -307,6 +308,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="run the two-request compile-cache self-test and exit")
     args = ap.parse_args(argv)
+    use_compile_cache()
     devices = (
         None if args.devices is None
         else args.devices if args.devices == "all"

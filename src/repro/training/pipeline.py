@@ -27,7 +27,6 @@ from typing import Any, Callable, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 PyTree = Any
 
@@ -99,12 +98,12 @@ def pipeline_forward(
     spec_p = jax.tree_util.tree_map(
         lambda _: P(axis), stage_params
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         per_rank,
         mesh=mesh,
         in_specs=(spec_p, P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x)
 

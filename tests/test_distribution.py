@@ -53,7 +53,7 @@ def test_data_parallel_train_step_matches_single_device():
 
             p1, _, m1 = jax.jit(step)(params, opt.init(params), batch)
 
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
             with mesh:
                 p_sh = params_shardings(cfg, mesh, jax.eval_shape(lambda: params))
                 b_sh = batch_shardings(mesh, jax.eval_shape(lambda: batch), 8)
@@ -124,7 +124,7 @@ def test_rl_envs_shard_over_data_axis():
             E = 16
             sims = jax.tree_util.tree_map(
                 lambda a: jnp.broadcast_to(a, (E,) + a.shape), sim0)
-            mesh = jax.make_mesh((8,), ("data",))
+            mesh = jax.make_mesh((8,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
             shard = lambda t: jax.tree_util.tree_map(
                 lambda x: jax.device_put(
                     x, NamedSharding(mesh, P(*(("data",) + (None,) * (x.ndim - 1))))),

@@ -313,9 +313,12 @@ def test_sweep_jit_cache_is_bounded():
     assert len(engine._SWEEP_FNS) == engine._SWEEP_CACHE_SIZE
 
 
-def test_cli_experiment_flag(tmp_path):
+def test_cli_experiment_flag(tmp_path, monkeypatch):
     """launch/sim.py --experiment runs a spec file end to end."""
     from repro.launch.sim import main as sim_main
+
+    # main() turns on the persistent compile cache; tests stay uncached
+    monkeypatch.setattr("repro.launch.sim.use_compile_cache", lambda: None)
 
     spec = tmp_path / "exp.json"
     experiments.Experiment(

@@ -248,3 +248,44 @@ def test_job_profiles_workload():
         assert 1 <= j.res <= 128
         assert j.runtime >= 60
         assert j.reqtime >= j.runtime
+
+
+def test_gantt_log_too_large_fails_before_compiling(tmp_path, monkeypatch):
+    """A Gantt log the device cannot hold is refused before compiling, with
+    the byte count and the way out in the message."""
+    from repro.launch import sim
+
+    monkeypatch.setattr(sim, "_device_bytes_limit", lambda: 10**6)
+    out = tmp_path / "run"
+    config = {"workload": "preset:fig3_small", "platform": 16, "out": str(out)}
+    cap = 20 * 200 + 10_000  # engine.default_batch_cap of 200 jobs
+    with pytest.raises(ValueError, match=f"{2 * cap * 16 * 4} bytes.*\"gantt\": false"):
+        run(config)
+    assert not (out / "metrics.json").exists()
+    run(dict(config, gantt=False))  # the way out runs
+    assert (out / "metrics.json").exists()
+
+
+@pytest.mark.parametrize("from_env", [False, True], ids=["checkout", "env"])
+def test_compile_cache_placement(tmp_path, monkeypatch, from_env):
+    """JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the cache sits at
+    the fixed <checkout>/.jax_cache/."""
+    import jax
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = use_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if from_env:
+        assert path == str(tmp_path) and after == before
+    else:
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == after == os.path.join(repo, ".jax_cache")
