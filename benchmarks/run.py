@@ -18,7 +18,7 @@ import os
 import time
 
 SECTIONS = ("speedup", "energy_grid", "fig1", "scale", "curie", "rl",
-            "dvfs", "forecast", "kernels", "roofline")
+            "dvfs", "forecast")
 
 
 def section(title):
@@ -47,9 +47,7 @@ def main() -> None:
         bench_dvfs,
         bench_energy,
         bench_forecast,
-        bench_kernels,
         bench_rl,
-        bench_roofline,
         bench_scale,
         bench_speedup,
     )
@@ -231,19 +229,6 @@ def main() -> None:
             bench_jobs=fc.get("bench_jobs"),
             jobs_per_s=fc.get("jobs_per_s"),
         )
-
-    if want("kernels"):
-        section("Kernel micro-benchmarks")
-        timed(
-            "kernels",
-            lambda: bench_kernels.main(
-                ["--seq", "2048" if args.full else "1024"]
-            ),
-        )
-
-    if want("roofline"):
-        section("Roofline table (from out/dryrun)")
-        timed("roofline", lambda: bench_roofline.main(["--mesh", "16x16"]))
 
     # total is the sum of the recorded sections (consistent under
     # --sections merges, where this run's wall time covers only a subset)

@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.policy import (
     PolicyParams,
     PowerPolicy,
@@ -926,6 +927,7 @@ def _power_step(s: SimState, const: EngineConst, cfg: EngineConfig) -> SimState:
     return s
 
 
+@jax.named_scope("process_batch")
 def process_batch(s: SimState, const: EngineConst, cfg: EngineConfig) -> SimState:
     """One atomic event batch at time s.t (SEMANTICS.md rules 1-8).
 
@@ -933,11 +935,15 @@ def process_batch(s: SimState, const: EngineConst, cfg: EngineConfig) -> SimStat
     ``const.policy`` flags — this function contains no policy-variant
     branching, static or otherwise.
     """
-    s = _complete_jobs(s)
-    s = _complete_transitions(s, const)
-    s = _scheduler_pass(s, const, cfg)
-    s = _start_jobs(s, const, cfg)
-    s = _power_step(s, const, cfg)
+    with jax.named_scope("complete"):
+        s = _complete_jobs(s)
+        s = _complete_transitions(s, const)
+    with jax.named_scope("scheduler_pass"):
+        s = _scheduler_pass(s, const, cfg)
+    with jax.named_scope("start_jobs"):
+        s = _start_jobs(s, const, cfg)
+    with jax.named_scope("power_step"):
+        s = _power_step(s, const, cfg)
     return s._replace(n_batches=s.n_batches + 1)
 
 
@@ -1076,6 +1082,7 @@ def _quiet_enabled(const: EngineConst, cfg: EngineConfig) -> bool:
     )
 
 
+@jax.named_scope("quiet_batch")
 def _quiet_batch(s: SimState, const: EngineConst, cfg: EngineConfig) -> SimState:
     """Stripped batch for quiet events (§Hot loop): transition completions
     and idle-timeout expiries only — no window scatter, no argsorts, no
@@ -1110,6 +1117,7 @@ def _quiet_batch(s: SimState, const: EngineConst, cfg: EngineConfig) -> SimState
     return s._replace(n_batches=s.n_batches + 1)
 
 
+@jax.named_scope("event_horizon")
 def event_horizon(
     s: SimState, const: EngineConst, cfg: EngineConfig
 ) -> Tuple[jax.Array, EventAux]:
@@ -1189,6 +1197,7 @@ def event_horizon(
     )
 
 
+@jax.named_scope("accrue_energy")
 def accrue_energy(
     s: SimState,
     t_next: jax.Array,
@@ -1350,7 +1359,8 @@ def run_sim(
             s = s._replace(t=nt)
             return process_batch(s, const, cfg)
 
-        out = jax.lax.while_loop(cond, body, s)
+        with jax.named_scope("loop"):
+            out = jax.lax.while_loop(cond, body, s)
         # cap-hit detection: the loop would have continued but for n_batches
         nt = next_time(out, const, cfg)
         return out._replace(truncated=(~all_done(out)) & (nt < INF))
@@ -1378,7 +1388,9 @@ def run_sim(
         nt, aux = event_horizon(s, const, cfg)
         return s, nt, aux
 
-    out, nt, _ = jax.lax.while_loop(cond, body, (s, nt0, aux0))
+    # the loop's own ops (its condition, the carry) fall in no other phase
+    with jax.named_scope("loop"):
+        out, nt, _ = jax.lax.while_loop(cond, body, (s, nt0, aux0))
     # cap-hit detection: the loop would have continued but for n_batches
     return out._replace(truncated=(~all_done(out)) & (nt < INF))
 
@@ -1427,7 +1439,8 @@ def run_sim_gantt(
         s = process_batch(s, const, cfg)
         return s, log
 
-    out, log = jax.lax.while_loop(cond, body, (s, log))
+    with jax.named_scope("loop"):
+        out, log = jax.lax.while_loop(cond, body, (s, log))
     nt = next_time(out, const, cfg)
     out = out._replace(truncated=(~all_done(out)) & (nt < INF))
     return out, log
@@ -1753,6 +1766,7 @@ class PendingSweep:
     _n_compiles: Optional[int]
     _cache_hit: bool
     _devices: Optional[int]
+    _span: spans.Begun  # the ``sweep`` span, begun by sweep_async
     _batch: Optional[SimBatch] = None
 
     def result(self) -> SimBatch:
@@ -1760,8 +1774,18 @@ class PendingSweep:
         (idempotent — the batch is cached after the first call)."""
         if self._batch is not None:
             return self._batch
+        try:
+            with spans.within(self._span):
+                with spans.span("sweep.wait"):
+                    jax.block_until_ready(self._out.energy)
+                with spans.span("sweep.gather"):
+                    self._batch = self._gather()
+        finally:
+            spans.end(self._span)
+        return self._batch
+
+    def _gather(self) -> SimBatch:
         out = self._out
-        jax.block_until_ready(out.energy)
         if int(out.energy.shape[0]) != self._k:  # drop masked pad rows
             out = jax.tree_util.tree_map(lambda a: a[: self._k], out)
         trunc = np.flatnonzero(np.asarray(out.truncated))
@@ -1784,11 +1808,10 @@ class PendingSweep:
             )
             for i in range(self._k)
         )
-        self._batch = SimBatch(
+        return SimBatch(
             states=out, metrics=metrics, n_compiles=self._n_compiles,
             cache_hit=self._cache_hit, devices=self._devices,
         )
-        return self._batch
 
 
 def _sweep_program(config: EngineConfig, cap: int, devices=None):
@@ -1839,42 +1862,63 @@ def sweep_async(
     scenarios = list(scenarios)
     if not scenarios:
         raise ValueError("sweep needs at least one scenario")
-    base_const = make_const(platform, config)
-    consts, plats = [], []
-    for sc in scenarios:
-        c, p = _scenario_const(sc, base_const, platform, config)
-        consts.append(c)
-        plats.append(p)
-    K = len(consts)
-    D = _resolve_devices(devices, config)
-    pad = 0 if D is None else (-K) % D
-    if pad:
-        # §Device-sharded sweeps pad/mask rule: pad rows reuse scenario 0's
-        # const so they trace identically to real rows; dropped on gather
-        consts = consts + [consts[0]] * pad
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *consts)
+    # the ``sweep`` span runs on until the returned handle's result()
+    top = spans.begin("sweep")
+    try:
+        with spans.within(top):
+            return _dispatch_sweep(
+                top, platform, workload, scenarios, config, job_capacity,
+                devices,
+            )
+    except BaseException:
+        spans.end(top)
+        raise
 
-    s0 = init_state(platform, workload, config, job_capacity=job_capacity)
-    cap = config.max_batches or default_batch_cap(len(workload))
-    # the cache key grows the padded grid width and the device count, so a
-    # sharded grid never reuses (or poisons) an unsharded program's entry
-    key = _static_trace_key(
-        platform, config, int(s0.job_status.shape[0]), cap
-    ) + (K + pad, D)
-    fn = _SWEEP_FNS.pop(key, None)
-    cache_hit = fn is not None
-    _CACHE_STATS["sweep_hits" if cache_hit else "sweep_misses"] += 1
-    if fn is None:
-        if len(_SWEEP_FNS) >= _SWEEP_CACHE_SIZE:
-            _SWEEP_FNS.popitem(last=False)  # evict least-recently-used
-        fn = _sweep_program(
-            config, cap, None if D is None else jax.devices()[:D]
-        )
-    _SWEEP_FNS[key] = fn
-    out = fn(s0, stacked)  # asynchronous dispatch — not blocked here
-    cache_size = getattr(fn, "_cache_size", None)
-    n_compiles = cache_size() if callable(cache_size) else None
-    return PendingSweep(out, plats, K, n_compiles, cache_hit, D)
+
+def _dispatch_sweep(
+    top, platform, workload, scenarios, config, job_capacity, devices
+) -> PendingSweep:
+    with spans.span("sweep.consts"):
+        base_const = make_const(platform, config)
+        consts, plats = [], []
+        for sc in scenarios:
+            c, p = _scenario_const(sc, base_const, platform, config)
+            consts.append(c)
+            plats.append(p)
+    with spans.span("sweep.stack"):
+        K = len(consts)
+        D = _resolve_devices(devices, config)
+        pad = 0 if D is None else (-K) % D
+        if pad:
+            # §Device-sharded sweeps pad/mask rule: pad rows reuse scenario
+            # 0's const so they trace identically to real rows; dropped on
+            # gather
+            consts = consts + [consts[0]] * pad
+        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *consts)
+    with spans.span("sweep.init"):
+        s0 = init_state(platform, workload, config, job_capacity=job_capacity)
+    with spans.span("sweep.dispatch"):
+        cap = config.max_batches or default_batch_cap(len(workload))
+        # the cache key grows the padded grid width and the device count,
+        # so a sharded grid never reuses (or poisons) an unsharded
+        # program's entry
+        key = _static_trace_key(
+            platform, config, int(s0.job_status.shape[0]), cap
+        ) + (K + pad, D)
+        fn = _SWEEP_FNS.pop(key, None)
+        cache_hit = fn is not None
+        _CACHE_STATS["sweep_hits" if cache_hit else "sweep_misses"] += 1
+        if fn is None:
+            if len(_SWEEP_FNS) >= _SWEEP_CACHE_SIZE:
+                _SWEEP_FNS.popitem(last=False)  # evict least-recently-used
+            fn = _sweep_program(
+                config, cap, None if D is None else jax.devices()[:D]
+            )
+        _SWEEP_FNS[key] = fn
+        out = fn(s0, stacked)  # asynchronous dispatch — not blocked here
+        cache_size = getattr(fn, "_cache_size", None)
+        n_compiles = cache_size() if callable(cache_size) else None
+    return PendingSweep(out, plats, K, n_compiles, cache_hit, D, top)
 
 
 def sweep(

@@ -34,7 +34,7 @@ import warnings
 from collections import deque
 from typing import Any, Iterator, Optional, Tuple
 
-from repro.core import engine
+from repro.core import engine, spans
 from repro.experiments.spec import Experiment, resolve_platform, resolve_workload
 
 
@@ -237,6 +237,16 @@ def _resolve_run(experiment: Experiment, platform, workload):
     return plat, cfg, grid, scenarios
 
 
+def _workload(experiment: Experiment, workload, replication: int):
+    """Replication ``replication``'s workload: the injected one (which
+    implies ``replications == 1``, guarded in ``_resolve_run``), else the
+    spec's, resolved (an SWF file is parsed here)."""
+    if workload is not None:
+        return workload
+    with spans.span("experiments.workload"):
+        return resolve_workload(experiment.workload, replication=replication)
+
+
 class StreamingRun:
     """Iterator of completed row-chunks from ``run(..., stream=True)``.
 
@@ -305,12 +315,7 @@ def run(
     n_compiles: Optional[int] = None
     t0 = time.perf_counter()
     for r in range(experiment.replications):
-        # an injected workload implies replications == 1 (guarded above)
-        wl = (
-            workload
-            if workload is not None
-            else resolve_workload(experiment.workload, replication=r)
-        )
+        wl = _workload(experiment, workload, r)
         with warnings.catch_warnings():
             # the engine layers warn per call; run() emits ONE aggregated
             # warning over the rows below, labelled with the grid points
@@ -422,13 +427,7 @@ def _run_stream(
             return chunk_rows
 
         for r in range(experiment.replications):
-            # an injected workload implies replications == 1 (guarded in
-            # _resolve_run)
-            wl = (
-                workload
-                if workload is not None
-                else resolve_workload(experiment.workload, replication=r)
-            )
+            wl = _workload(experiment, workload, r)
             if single:
                 pending.append((grid, r, "single", wl))
                 while len(pending) > _STREAM_DEPTH:
@@ -461,20 +460,21 @@ def _run_stream(
 
 
 def write_outputs(result: ExperimentResult, out_dir: str) -> None:
-    os.makedirs(out_dir, exist_ok=True)
-    with open(os.path.join(out_dir, "metrics.json"), "w") as f:
-        json.dump(_metrics_payload(result), f, indent=2, sort_keys=True)
-        f.write("\n")
-    rows = result.rows
-    lead = ["scheduler", "timeout", "forecast", "platform", "replication"]
-    cols = sorted({k for r in rows for k in r}, key=lambda c: (
-        lead.index(c) if c in lead else len(lead),
-        c,
-    ))
-    with open(os.path.join(out_dir, "rows.csv"), "w", newline="") as f:
-        w = csv.DictWriter(f, fieldnames=cols)
-        w.writeheader()
-        w.writerows(rows)
+    with spans.span("experiments.write"):
+        os.makedirs(out_dir, exist_ok=True)
+        with open(os.path.join(out_dir, "metrics.json"), "w") as f:
+            json.dump(_metrics_payload(result), f, indent=2, sort_keys=True)
+            f.write("\n")
+        rows = result.rows
+        lead = ["scheduler", "timeout", "forecast", "platform", "replication"]
+        cols = sorted({k for r in rows for k in r}, key=lambda c: (
+            lead.index(c) if c in lead else len(lead),
+            c,
+        ))
+        with open(os.path.join(out_dir, "rows.csv"), "w", newline="") as f:
+            w = csv.DictWriter(f, fieldnames=cols)
+            w.writeheader()
+            w.writerows(rows)
 
 
 def run_file(path: str) -> ExperimentResult:

@@ -81,6 +81,19 @@ def test_sl004_flags_both_contract_halves():
     assert "ref.*_reference" in text
 
 
+def test_sl005_flags_host_spans_in_traced_bodies():
+    """A host span, a profiler annotation or a host clock reachable from
+    run_sim would freeze at trace time; a jax.named_scope is fine."""
+    findings = _run(os.path.join(FIXTURES, "sl005"), only=["SL005"])
+    spans_found = [f for f in findings if "host span or clock" in f.msg]
+    text = "\n".join(f.msg for f in spans_found)
+    assert "`time.perf_counter`" in text
+    assert "`spans.span`" in text
+    assert "`TraceAnnotation`" in text
+    assert len(spans_found) == 3  # the named scopes are not flagged
+    assert all("`process_batch`" in f.msg for f in spans_found)
+
+
 def test_waiver_silences_flagged_line():
     """An `ignore[SL005,SL001]` comma-list comment above the violation
     keeps the whole waived tree clean."""

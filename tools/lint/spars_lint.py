@@ -32,8 +32,10 @@ Passes (each emits ``file:line RULE message``):
   in ``kernels/ref.py``, a zero-size short-circuit, and a conditional
   untileable-fallback route to the reference.
 * **SL005 tracer-leak / purity** — no ``np.``/``print``/``warnings`` host
-  calls and no ``bool()``/``int()``/``float()``/``.item()`` coercion of
-  traced values (``s.*`` / ``const.*``) inside jit-traced bodies.
+  calls, no host span or clock (``spans.*``, ``TraceAnnotation``,
+  ``time.perf_counter``) and no ``bool()``/``int()``/``float()``/
+  ``.item()`` coercion of traced values (``s.*`` / ``const.*``) inside
+  jit-traced bodies.
 * **SL006 metrics-row consistency** — every ``SimMetrics`` field is
   consumed by ``row()`` (transitively through its helper methods), so a
   gated field cannot ship without its gated column.
@@ -593,6 +595,8 @@ def check_sl004(root: str) -> List[Finding]:
 _TRACED_VARS = {"s", "const", "state"}
 _HOST_COERCIONS = {"bool", "int", "float"}
 _HOST_METHODS = {"item", "tolist"}
+# host spans and clocks: inside a traced body they run once, at trace time
+_HOST_CLOCKS = {"perf_counter", "TraceAnnotation"}
 
 
 def _traced_scope(root: str) -> List[Tuple[_File, ast.FunctionDef]]:
@@ -610,6 +614,14 @@ def _traced_scope(root: str) -> List[Tuple[_File, ast.FunctionDef]]:
             if fn.args.args and fn.args.args[0].arg == "s":
                 out.append((policy, fn))
     return out
+
+
+def _host_span_finding(what: str, fn: str) -> str:
+    return (
+        f"host span or clock `{what}` inside jit-traced body `{fn}` — it "
+        "runs once, at trace time, and times nothing on the device; use "
+        "jax.named_scope there, and spans around the host-side caller"
+    )
 
 
 def check_sl005(root: str) -> List[Finding]:
@@ -630,6 +642,13 @@ def check_sl005(root: str) -> List[Finding]:
                         f"jit-traced body `{fn.name}` — warn from the "
                         "host driver instead"
                     )
+                elif n.value.id == "spans" or n.attr in _HOST_CLOCKS:
+                    finding = _host_span_finding(
+                        f"{n.value.id}.{n.attr}", fn.name)
+            elif isinstance(n, ast.Attribute) and n.attr in _HOST_CLOCKS:
+                finding = _host_span_finding(n.attr, fn.name)
+            elif isinstance(n, ast.Name) and n.id in _HOST_CLOCKS:
+                finding = _host_span_finding(n.id, fn.name)
             elif isinstance(n, ast.Call) and isinstance(n.func, ast.Name):
                 if n.func.id == "print":
                     finding = (
