@@ -1757,7 +1757,8 @@ class PendingSweep:
     asynchronous — the device arrays inside are futures); host work can
     overlap with the device computation until :meth:`result` blocks. The
     streaming experiment runner dispatches chunk ``k+1`` before draining
-    chunk ``k`` through this handle.
+    chunk ``k`` through this handle; :meth:`result` then gathers the
+    stacked final state to the host in one transfer.
     """
 
     _out: SimState  # padded stacked final states (leading axis K + pad)
@@ -1785,10 +1786,21 @@ class PendingSweep:
         return self._batch
 
     def _gather(self) -> SimBatch:
+        """One host transfer of the stacked final state, then every lane's
+        metrics from numpy views of that copy (§Device-sharded sweeps).
+
+        ``jax.device_get`` starts each leaf's device-to-host copy before
+        collecting any (a sharded leaf is assembled from its shards), so a
+        gather is one round of transfers, not one per lane and field. Peak
+        host memory is K × (state bytes per lane). ``SimBatch.states``
+        stays the device tree with the pad rows dropped.
+        """
+        k = self._k
         out = self._out
-        if int(out.energy.shape[0]) != self._k:  # drop masked pad rows
-            out = jax.tree_util.tree_map(lambda a: a[: self._k], out)
-        trunc = np.flatnonzero(np.asarray(out.truncated))
+        host = jax.tree_util.tree_map(lambda a: a[:k], jax.device_get(out))
+        if int(out.energy.shape[0]) != k:  # drop masked pad rows
+            out = jax.tree_util.tree_map(lambda a: a[:k], out)
+        trunc = np.flatnonzero(host.truncated)
         if trunc.size:
             warnings.warn(
                 f"sweep scenario(s) {[int(i) for i in trunc]} hit the batch "
@@ -1803,10 +1815,10 @@ class PendingSweep:
 
         metrics = tuple(
             metrics_from_state(
-                jax.tree_util.tree_map(lambda a, i=i: a[i], out),
+                jax.tree_util.tree_map(lambda a, i=i: a[i], host),
                 self._plats[i],
             )
-            for i in range(self._k)
+            for i in range(k)
         )
         return SimBatch(
             states=out, metrics=metrics, n_compiles=self._n_compiles,

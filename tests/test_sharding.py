@@ -139,6 +139,20 @@ def test_streaming_matches_blocking_bytes(tmp_path):
         assert (out / p).read_bytes() == want, f"{p} diverged from blocking"
 
 
+@pytest.mark.parametrize("chunk", [1, 2])
+def test_streaming_bytes_match_blocking_at_chunk_size(tmp_path, chunk):
+    """Every chunk size gathers to the blocking path's metrics.json /
+    rows.csv bytes, one gather per chunk."""
+    out = tmp_path / "out"
+    exp = _stream_spec(str(out))
+    experiments.run(exp)
+    golden = {p: (out / p).read_bytes() for p in ("metrics.json", "rows.csv")}
+    sr = experiments.run(exp, stream=True, chunk_scenarios=chunk)
+    assert [len(c) for c in sr] == [chunk] * (4 // chunk)
+    for p, want in golden.items():
+        assert (out / p).read_bytes() == want, p
+
+
 def test_streaming_partial_prefix_on_disk(tmp_path):
     """An abandoned stream leaves a valid rows-so-far prefix on disk."""
     out = tmp_path / "out"
