@@ -80,6 +80,7 @@ def reference(config: dict, jobs: dict, label: str, timeout: Optional[int],
     eng = config["engine"]
     return des.simulate(plat, des.prepare_jobs(jobs, plat.n), label, timeout,
                         node_order=eng["node_order"], window=int(eng["window"]),
+                        allocation=eng.get("allocation", "any"),
                         energy_dtype=energy_dtype)
 
 

@@ -56,6 +56,7 @@ class Entry:
             schedulers=self.schedulers,
             timeouts=self.timeouts,
             node_order=eng["node_order"],
+            allocation=eng.get("allocation", "any"),
             grouped_tables=bool(eng["grouped_tables"]),
             window=int(eng["window"]),
             out=out_dir,

@@ -1,7 +1,8 @@
 """Share of the window's engine calls, as the program's ``sweep`` spans
 time them, spent gathering the lanes after the device loop: its
-``sweep.gather`` spans (pad drop, per-lane slices and metrics). Without
-them, nothing."""
+``sweep.gather`` spans (one host transfer of the stacked final state, the
+pad drop, and each lane's metrics from that host copy). Without them,
+nothing."""
 import program_spans
 
 

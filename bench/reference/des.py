@@ -13,10 +13,14 @@ and energy accrues per (node group, power state) between batches.
 
 Scope: the schedulers ``FCFS``/``EASY`` x ``PSUS``/``PSAS``/``PSAS+IPM``,
 any number of node groups with their own power, switch delays and speed,
-node order ``id`` or ``cheap`` (active watts per unit of speed), and the
-scheduler window. Time is whole seconds. ``energy_dtype`` sets the
-precision of the energy ledger: float64 is the reference; a lower one
-(``bfloat16``) is the control that the check must refuse.
+node order ``id`` or ``cheap`` (active watts per unit of speed), the
+scheduler window, and allocation ``any`` (a job takes the first nodes of
+the allocation order, whatever their groups) or ``partition`` (a job runs
+within one group: the group whose ``res``-th eligible node comes first in
+the allocation order, and none where no group has that many; a job wider
+than every group never starts). Time is whole seconds. ``energy_dtype``
+sets the precision of the energy ledger: float64 is the reference; a lower
+one (``bfloat16``) is the control that the check must refuse.
 """
 from __future__ import annotations
 
@@ -144,10 +148,14 @@ def simulate(
     *,
     node_order: str = "id",
     window: int = 32,
+    allocation: str = "any",
     terminate_overrun: bool = False,
     energy_dtype=np.float64,
 ) -> Result:
     """Run ``jobs`` (as :func:`prepare_jobs` returns them) to completion."""
+    if allocation not in ("any", "partition"):
+        raise ValueError(f"allocation {allocation!r}: 'any' or 'partition'")
+    partition = allocation == "partition"
     backfill, eager, sleep_on, ipm_on = policy_flags(label)
     p = platform
     N = p.n
@@ -203,12 +211,18 @@ def simulate(
         shadow = extra = None
         for j in q:
             k = int(res[j])
+            if partition:
+                rest = elig[head:].copy()
             if elig.size - head < k:
+                ok = False
+            elif partition and not group_first(elig[head:], k):
                 ok = False
             else:
                 chosen = elig[head:head + k]
                 rdy = r[chosen[-1]]
                 ok = shadow is None or (rdy + req[j] <= shadow or k <= extra)
+            if partition and not ok:  # a pick the backfill test refused
+                elig[head:] = rest
             if ok:
                 head += k
                 njob[chosen] = j
@@ -223,6 +237,25 @@ def simulate(
                 if not backfill:
                     return
                 shadow, extra = easy_shadow(k)
+
+    def group_first(order, k):
+        """The partition rule on ``order``, the eligible nodes in allocation
+        order: of the groups with at least ``k`` nodes there, the one whose
+        ``k``-th node comes first wins, and its first ``k`` nodes move to
+        the front of ``order`` (in place), every other node keeping its
+        place in the order behind them. False, and ``order`` untouched,
+        where no group has ``k`` nodes."""
+        best = None
+        for g in range(p.n_groups):
+            at = np.flatnonzero(p.gid[order] == g)
+            if at.size >= k and (best is None or at[k - 1] < best[k - 1]):
+                best = at
+        if best is None:
+            return False
+        front = np.zeros(order.size, bool)
+        front[best[:k]] = True
+        order[:] = np.concatenate([order[front], order[~front]])
+        return True
 
     def easy_shadow(k):
         rel = ready(t)

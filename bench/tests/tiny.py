@@ -23,20 +23,46 @@ def _dump(obj, path):
         json.dump(obj, f, indent=2)
 
 
-def make(tmp: str) -> str:
-    """``<tmp>/bench`` with ``<tmp>/BENCHMARK.json``: every cell of the real
-    benchmark, each configuration cut to 16 nodes and 40 jobs a segment,
-    each pool to 2 segments and each grid to 2 schedulers x 2 timeouts per
-    chip."""
+NODES = 16  # the cut machine's size, about
+
+
+def cut(cfg: dict) -> dict:
+    """``cfg`` on about :data:`NODES` nodes and 40 jobs a segment.
+
+    A homogeneous machine gets 16 nodes. A machine of node groups keeps
+    every group: each group's count is scaled to its share of 16, rounded,
+    and never below 2, and the machine is their sum. Its trace's widest job
+    then fits the largest group where the configuration allocates within
+    one group (``"allocation": "partition"``), and the machine otherwise,
+    so that every job can start."""
+    plat, trace = cfg["platform"], cfg["trace"]
+    groups = plat.get("node_groups")
+    if groups:
+        total = sum(int(g["count"]) for g in groups)
+        for g in groups:
+            g["count"] = max(2, round(NODES * int(g["count"]) / total))
+        n = sum(g["count"] for g in groups)
+        widest = (max(g["count"] for g in groups)
+                  if cfg["engine"].get("allocation") == "partition" else n)
+        trace["max_res"] = min(int(trace.get("max_res") or n), widest)
+    else:
+        n = NODES
+    plat["nb_nodes"] = n
+    trace.update(nb_res=n, mean_interarrival=200.0)
+    cfg["trace_jobs"] = 40
+    return cfg
+
+
+def make(tmp: str, root: str = ROOT) -> str:
+    """``<tmp>/bench`` with ``<tmp>/BENCHMARK.json``: every cell of the
+    benchmark at ``root``, each configuration cut by :func:`cut`, each pool
+    to 2 segments and each grid to 2 schedulers x 2 timeouts per chip."""
     bench = os.path.join(tmp, "bench")
-    shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
-        "__pycache__", "tests"))
-    spec = _load(os.path.join(ROOT, "BENCHMARK.json"))
+    shutil.copytree(os.path.join(root, "bench"), bench,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    spec = _load(os.path.join(root, "BENCHMARK.json"))
     for c in spec["configs"]:
-        cfg = _load(os.path.join(ROOT, c["file"]))
-        cfg["platform"]["nb_nodes"] = 16
-        cfg["trace"].update(nb_res=16, mean_interarrival=200.0)
-        cfg["trace_jobs"] = 40
+        cfg = cut(_load(os.path.join(root, c["file"])))
         _dump(cfg, os.path.join(tmp, c["file"]))
     for w in spec["workloads"]:
         path = os.path.join(bench, "traffic", f"{w['traffic']}.json")
